@@ -25,6 +25,7 @@ def main() -> None:
 
     from . import paper_figures
     from . import engine_micro
+    from . import roofline
 
     sections = [
         ("fig2", paper_figures.fig2_comm_cost),
@@ -34,12 +35,8 @@ def main() -> None:
         ("fig6", paper_figures.fig6_aggregated_cost),
         ("validate", paper_figures.engine_validation),
         ("engine", engine_micro.bench_engine),
+        ("roofline", roofline.bench_rows),
     ]
-    try:
-        from . import roofline
-        sections.append(("roofline", roofline.bench_rows))
-    except Exception:
-        pass
 
     print("name,us_per_call,derived")
     for name, fn in sections:

@@ -1,22 +1,14 @@
-"""Shims over jax API surface that moved between the versions we support.
+"""Shim over the jax API surface the engine calls in one fixed form.
 
-* ``shard_map`` graduated from ``jax.experimental.shard_map`` to
-  ``jax.shard_map``, renaming ``check_rep`` to ``check_vma`` on the way.
-* Pallas' ``TPUCompilerParams`` became ``CompilerParams`` (handled
-  locally in ``repro.kernels`` since only kernels need it).
+``shard_map`` lives at ``jax.shard_map`` and spells its replication
+check ``check_vma``; the engine always turns the check off.
 """
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:  # jax < 0.6: experimental location, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -19,12 +19,17 @@ actually run concurrently on GPU meshes, and
 CI-testable on a single CPU host.  Like :func:`enable_x64` it must run
 before JAX initializes its backends; afterwards it warns and leaves
 the live configuration alone rather than crashing the job.
+
+:func:`enable_compilation_cache` places JAX's persistent compilation
+cache: where ``JAX_COMPILATION_CACHE_DIR`` says, else at a fixed
+``.jax_cache`` directory of the checkout.
 """
 
 from __future__ import annotations
 
 import os
 import warnings
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -135,6 +140,23 @@ def configure_platform(platform: str | None = None,
     if platform is not None:
         jax.config.update("jax_platform_name", platform)
     return True
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, names the directory and
+    nothing else is configured (JAX reads the variable itself).
+    Otherwise the cache lives at ``<checkout>/.jax_cache`` — a fixed
+    path, because the path is part of every entry's key, and one that
+    git ignores.  Call it before the first compilation.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[2] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 #: Largest flat pair index the all-pairs join kernel can form without

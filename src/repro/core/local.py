@@ -443,11 +443,11 @@ def groupby_sum(rel: Relation, keys: Tuple[str, ...], value: str,
     overflow = n_groups > out_cap
 
     # Segment ids are non-decreasing over the valid prefix — exactly the
-    # sorted-ids case the Pallas kernel prunes to the diagonal band.
+    # sorted-ids case whose staircase the Pallas kernel walks.
     # Invalid / overflowed rows get id out_cap, dropped by the kernel.
     seg = jnp.where(sorted_valid, seg_id, out_cap)
     sums = ops.segment_sum(jnp.where(sorted_valid, sorted_val, 0.0), seg,
-                           out_cap, backend=backend)
+                           out_cap, backend=backend, indices_are_sorted=True)
     dest = jnp.where(sorted_valid & (seg_id < out_cap), seg_id, out_cap)
     out_cols = {}
     for k, sk in zip(keys, sorted_keys):

@@ -49,7 +49,7 @@ _SKETCH_SALT = 3  # detection hop salt, distinct from routing salts 0..2
 
 def heavy_hitters(values: np.ndarray, threshold: float, *,
                   n_buckets: int = 4096,
-                  use_pallas: Optional[bool] = None,
+                  backend: str = "auto",
                   ) -> Tuple[np.ndarray, np.ndarray]:
     """Keys of ``values`` with frequency strictly above ``threshold``.
 
@@ -69,7 +69,7 @@ def heavy_hitters(values: np.ndarray, threshold: float, *,
         return np.empty((0,), np.int32), np.empty((0,), np.float64)
     jvals = jnp.asarray(vals)
     hist = bucket_counts(jvals, jnp.ones(vals.shape, jnp.bool_), n_buckets,
-                         salt=_SKETCH_SALT, use_pallas=use_pallas)
+                         salt=_SKETCH_SALT, backend=backend)
     hot = np.asarray(hist) > threshold
     if not hot.any():
         return np.empty((0,), np.int32), np.empty((0,), np.float64)
@@ -171,7 +171,7 @@ def detect_chain_skew(query: ChainQuery,
                       edge_lists: Sequence[Tuple[np.ndarray, np.ndarray]],
                       k: int, *, slack: float = 1.25,
                       n_buckets: int = 4096,
-                      use_pallas: Optional[bool] = None,
+                      backend: str = "auto",
                       ) -> Optional[SkewSplitPlan]:
     """Build the exact SharesSkew plan for a chain of edge relations,
     or ``None`` when no join attribute has a key above the balance
@@ -192,11 +192,11 @@ def detect_chain_skew(query: ChainQuery,
         hl, _ = heavy_hitters(
             np.asarray(edge_lists[d][1]),
             balance_threshold(sizes[d], base[d], slack),
-            n_buckets=n_buckets, use_pallas=use_pallas)
+            n_buckets=n_buckets, backend=backend)
         hr, _ = heavy_hitters(
             np.asarray(edge_lists[d + 1][0]),
             balance_threshold(sizes[d + 1], base[d], slack),
-            n_buckets=n_buckets, use_pallas=use_pallas)
+            n_buckets=n_buckets, backend=backend)
         heavy.append(np.unique(np.concatenate([hl, hr])))
     if all(h.size == 0 for h in heavy):
         return None

@@ -29,7 +29,7 @@ kernel:
   the grid pipeline double-buffers each block's DMA against the
   previous block's compute — and prunes off-band tiles with
   ``pl.when`` (sorted inputs leave only the diagonal band dense).
-  Backend policy follows ``repro.kernels.ops``: ``pallas`` on TPU,
+  Backend policy follows ``repro.kernels.backend``: ``pallas`` on TPU,
   ``interpret`` for CPU validation, ``ref`` (= ``jnp.searchsorted``,
   the staged path's own op) elsewhere.
 
@@ -45,11 +45,15 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax moved TPUCompilerParams -> CompilerParams across versions; accept both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from . import backend as _backend
+
+# Block row of the (1, N) lane layout; a typed int32 so that index maps
+# stay 32-bit under x64.
+_ROW = np.int32(0)
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -170,8 +174,10 @@ def _probe_kernel(q_ref, r_ref, lo_ref, hi_ref, *, block_r: int):
 
     @pl.when((r_max >= q_min) & (r_min <= q_max))   # boundary band: compare
     def _band():
-        lt = jnp.sum(r[None, :] < q[:, None], axis=1).astype(jnp.int32)
-        le = jnp.sum(r[None, :] <= q[:, None], axis=1).astype(jnp.int32)
+        lt = jnp.sum((r[None, :] < q[:, None]).astype(jnp.int32), axis=1,
+                     dtype=jnp.int32)
+        le = jnp.sum((r[None, :] <= q[:, None]).astype(jnp.int32), axis=1,
+                     dtype=jnp.int32)
         lo_ref[...] += lt[None, :]
         hi_ref[...] += le[None, :]
 
@@ -186,7 +192,10 @@ def probe_counts_pallas(queries: jnp.ndarray, sorted_keys: jnp.ndarray, *,
     ``lo = #{r < q}``, ``hi = #{r ≤ q}`` — equal to ``searchsorted``
     left/right.  Sorted-key padding uses the dtype sentinel; the counts
     are clamped to the true key count so sentinel padding never leaks
-    (the same clamp the callers apply with the valid count)."""
+    (the same clamp the callers apply with the valid count).
+
+    Both columns lie along the lane axis, ``(1, n)`` tiled by
+    ``(1, block)`` blocks, as Mosaic's (8, 128) block rule asks."""
     nq, nr = queries.shape[0], sorted_keys.shape[0]
     sentinel = _key_sentinel(sorted_keys.dtype)
     block_q = min(block_q, max(128, 1 << (max(nq, 1) - 1).bit_length()))
@@ -202,21 +211,22 @@ def probe_counts_pallas(queries: jnp.ndarray, sorted_keys: jnp.ndarray, *,
         functools.partial(_probe_kernel, block_r=block_r),
         grid=(n_qb, n_rb),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, block_r), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, block_q), lambda i, j: (_ROW, i)),
+            pl.BlockSpec((1, block_r), lambda i, j: (_ROW, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, block_q), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, block_q), lambda i, j: (_ROW, i)),
+            pl.BlockSpec((1, block_q), lambda i, j: (_ROW, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_qb, block_q), jnp.int32),
-            jax.ShapeDtypeStruct((n_qb, block_q), jnp.int32),
+            jax.ShapeDtypeStruct((1, n_qb * block_q), jnp.int32),
+            jax.ShapeDtypeStruct((1, n_qb * block_q), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q.reshape(n_qb, block_q), r.reshape(n_rb, block_r))
+        name="probe_counts",
+    )(q.reshape(1, -1), r.reshape(1, -1))
     lo = jnp.minimum(lo.reshape(-1)[:nq], nr)
     hi = jnp.minimum(hi.reshape(-1)[:nq], nr)
     return lo, hi
@@ -225,12 +235,21 @@ def probe_counts_pallas(queries: jnp.ndarray, sorted_keys: jnp.ndarray, *,
 def probe_counts(queries: jnp.ndarray, sorted_keys: jnp.ndarray, *,
                  backend: str = "auto", block_q: int = 512,
                  block_r: int = 512) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Dispatching wrapper (policy of ``repro.kernels.ops``): ``ref``
+    """Dispatching wrapper (policy of ``repro.kernels.backend``): ``ref``
     is ``jnp.searchsorted`` left/right — the exact op the staged path
     runs, so the fused pipeline is bit-identical to the oracle on every
-    backend that resolves to it."""
-    b = backend if backend != "auto" else (
-        "pallas" if jax.default_backend() == "tpu" else "ref")
+    backend that resolves to it.
+
+    The TPU has no 64-bit vector lanes, so 64-bit keys on the
+    ``pallas`` backend raise ``TypeError`` (x64 joins on the chip take
+    ``join_impl="sort_merge"``)."""
+    b = _backend.resolve(backend)
+    if b == "pallas" and max(queries.dtype.itemsize,
+                             sorted_keys.dtype.itemsize) == 8:
+        raise TypeError(
+            f"probe_counts: the Pallas TPU kernel takes 32-bit keys, got "
+            f"{queries.dtype}/{sorted_keys.dtype}; use "
+            f"join_impl='sort_merge' for 64-bit keys")
     if b == "ref":
         lo = jnp.searchsorted(sorted_keys, queries, side="left")
         hi = jnp.searchsorted(sorted_keys, queries, side="right")

@@ -15,18 +15,33 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax moved TPUCompilerParams -> CompilerParams across versions; accept both.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from . import backend as _backend
+
+# Block row of the (1, N) lane layout; a typed int32 so that index maps
+# stay 32-bit under x64.
+_ROW = np.int32(0)
 
 _KNUTH = 2654435761
 _SALTS = (0x9E3779B9, 0x85EBCA6B, 0xC2B2AE35, 0x27D4EB2F)
 
 
+def _fold_u32(x: jnp.ndarray) -> jnp.ndarray:
+    """Keys as the 32-bit words the hash mixes: 64-bit keys fold high
+    xor low word (``repro.core.hashing.bucket_hash``'s convention), so
+    the kernel itself only ever sees 32-bit lanes."""
+    if x.dtype.itemsize == 8:
+        u64 = x.astype(jnp.uint64)
+        return (u64 ^ (u64 >> jnp.uint64(32))).astype(jnp.uint32)
+    return x.astype(jnp.uint32)
+
+
 def _bucket_hash(u: jnp.ndarray, n_buckets: int, salt: int) -> jnp.ndarray:
-    """Must match repro.core.hashing.bucket_hash bit-for-bit."""
+    """Must match repro.core.hashing.bucket_hash bit-for-bit (on keys
+    already folded to 32 bits by :func:`_fold_u32`)."""
     u = u.astype(jnp.uint32)
     u = (u ^ jnp.uint32(_SALTS[salt % len(_SALTS)])) * jnp.uint32(_KNUTH)
     u = u ^ (u >> jnp.uint32(15))
@@ -40,7 +55,7 @@ def _kernel(keys_ref, valid_ref, out_ref, *, n_buckets: int, k_pad: int,
     keys = keys_ref[0, :]
     valid = valid_ref[0, :] != 0
     b = _bucket_hash(keys, n_buckets, salt)
-    b = jnp.where(valid, b, k_pad)  # invalid rows land outside [0, k_pad)
+    b = jnp.where(valid, b, jnp.int32(k_pad))  # invalid rows: outside [0, k_pad)
     onehot = (
         b[:, None] == jax.lax.broadcasted_iota(jnp.int32, (block, k_pad), 1)
     ).astype(jnp.float32)
@@ -55,13 +70,21 @@ def hash_histogram(keys: jnp.ndarray, valid: jnp.ndarray, n_buckets: int, *,
                    interpret: bool = False) -> jnp.ndarray:
     """Fused bucket_hash + per-block histogram.
 
-    keys/valid: (N,) int32/bool.  Returns (ceil(N/block), n_buckets) int32
-    counts; column j of row i counts block-i keys hashing to bucket j.
+    keys/valid: (N,) int32|int64/bool.  Returns (ceil(N/block), n_buckets)
+    int32 counts; column j of row i counts block-i keys hashing to
+    bucket j.
+
+    Inputs are laid out along the lane axis, ``(1, N)`` tiled by
+    ``(1, block)`` blocks, and each block's histogram row lands at lane
+    offset ``i * k_pad`` of a ``(1, n_blocks * k_pad)`` output: Mosaic
+    needs a block's last two dims (8, 128)-aligned or equal to the
+    array's, which a ``(1, block)`` tile of an ``(n_blocks, block)``
+    array is not.
     """
     n = keys.shape[0]
     block = min(block, max(128, 1 << (max(n, 1) - 1).bit_length()))
     pad_n = -n % block
-    keys_p = jnp.pad(keys, (0, pad_n))
+    keys_p = jnp.pad(_fold_u32(keys), (0, pad_n))
     valid_p = jnp.pad(valid.astype(jnp.int32), (0, pad_n))
     n_blocks = (n + pad_n) // block
     k_pad = max(128, -(-n_buckets // 128) * 128)
@@ -71,35 +94,36 @@ def hash_histogram(keys: jnp.ndarray, valid: jnp.ndarray, n_buckets: int, *,
                           salt=salt, block=block),
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
+            pl.BlockSpec((1, block), lambda i: (_ROW, i)),
+            pl.BlockSpec((1, block), lambda i: (_ROW, i)),
         ],
-        out_specs=pl.BlockSpec((1, k_pad), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blocks, k_pad), jnp.int32),
-        compiler_params=_COMPILER_PARAMS(
+        out_specs=pl.BlockSpec((1, k_pad), lambda i: (_ROW, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n_blocks * k_pad), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(keys_p.reshape(n_blocks, block), valid_p.reshape(n_blocks, block))
-    return out[:, :n_buckets]
+        name="hash_histogram",
+    )(keys_p.reshape(1, -1), valid_p.reshape(1, -1))
+    return out.reshape(n_blocks, k_pad)[:, :n_buckets]
 
 
 def bucket_counts(keys: jnp.ndarray, valid: jnp.ndarray, n_buckets: int, *,
                   salt: int = 0, block: int = 1024,
-                  use_pallas: bool | None = None) -> jnp.ndarray:
+                  backend: str = "auto") -> jnp.ndarray:
     """Global bucket-load histogram of one map-phase shuffle hop.
 
     This is how the chain-join executor sizes and diagnoses a round:
     the histogram's max is the most-loaded reducer (skew).  On TPU the
     fused Pallas hash+histogram kernel does it in one pass over HBM;
     elsewhere (CPU tests, SimGrid under vmap) an equivalent jnp
-    scatter-add with bit-identical hash semantics.
+    scatter-add with bit-identical hash semantics
+    (``backend`` as in ``repro.kernels.backend``).
     """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
-    if use_pallas:
-        return hash_histogram(keys, valid, n_buckets, salt=salt,
-                              block=block).sum(axis=0)
-    b = _bucket_hash(keys, n_buckets, salt)
+    b = _backend.resolve(backend)
+    if b != "ref":
+        return hash_histogram(keys, valid, n_buckets, salt=salt, block=block,
+                              interpret=(b == "interpret")).sum(axis=0)
+    b = _bucket_hash(_fold_u32(keys), n_buckets, salt)
     return (jnp.zeros((n_buckets,), jnp.int32)
             .at[b].add(valid.astype(jnp.int32), mode="drop"))
 

@@ -1,44 +1,32 @@
-"""Jit'd dispatch wrappers over the Pallas kernels.
-
-Backend policy:
-  * "pallas"    — compiled Pallas (TPU target).
-  * "interpret" — Pallas interpret mode (kernel body executed in Python;
-                  the CPU validation path used by tests).
-  * "ref"       — the pure-jnp oracle (default on CPU: fastest correct
-                  path where no Mosaic backend exists).
-  * "auto"      — pallas on TPU, ref elsewhere.
-"""
+"""Jit'd dispatch wrappers over the Pallas kernels (backend policy:
+``repro.kernels.backend``)."""
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from . import backend as _backend
 from . import flash_attention as _fa
 from . import hash_partition as _hp
 from . import ref
 from . import segment_sum as _ss
 
 
-def _resolve(backend: str) -> str:
-    if backend != "auto":
-        return backend
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
-
 def segment_sum(values, segment_ids, num_segments: int,
-                backend: str = "auto") -> jnp.ndarray:
-    b = _resolve(backend)
+                backend: str = "auto", *,
+                indices_are_sorted: bool = False) -> jnp.ndarray:
+    b = _backend.resolve(backend)
     if b == "ref":
         return ref.segment_sum(values.astype(jnp.float32), segment_ids,
                                num_segments)
     return _ss.segment_sum(values, segment_ids, num_segments,
+                           indices_are_sorted=indices_are_sorted,
                            interpret=(b == "interpret"))
 
 
 def hash_histogram(keys, valid, n_buckets: int, *, salt: int = 0,
                    block: int = 1024, backend: str = "auto") -> jnp.ndarray:
-    b = _resolve(backend)
+    b = _backend.resolve(backend)
     if b == "ref":
         n = keys.shape[0]
         block_r = min(block, max(128, 1 << (max(n, 1) - 1).bit_length()))
@@ -53,7 +41,7 @@ def hash_histogram(keys, valid, n_buckets: int, *, salt: int = 0,
 def flash_attention(q, k, v, *, causal: bool = True, scale=None,
                     backend: str = "auto", block_q: int = 128,
                     block_kv: int = 128) -> jnp.ndarray:
-    b = _resolve(backend)
+    b = _backend.resolve(backend)
     if b == "ref":
         return ref.attention(q, k, v, causal=causal, scale=scale)
     return _fa.flash_attention(q, k, v, causal=causal, scale=scale,

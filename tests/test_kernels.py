@@ -11,7 +11,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.hash_partition import hash_histogram, partition_offsets
+from repro.kernels.hash_partition import (bucket_counts, hash_histogram,
+                                          partition_offsets)
 from repro.kernels.segment_sum import segment_sum
 
 
@@ -74,6 +75,19 @@ class TestHashHistogram:
             assert lo == pos
             pos = hi
         assert pos == int(h.sum())
+
+
+def test_x64_histogram_matches_folded_hash():
+    """The kernel's 64-bit fold is the engine hash's: interpret-mode
+    counts equal ``core.hashing.bucket_hash`` bucket loads."""
+    from repro.core.hashing import bucket_hash
+    with jax.enable_x64(True):
+        rng = np.random.default_rng(0)
+        keys = jnp.asarray(rng.integers(0, 1 << 40, 1000), jnp.int64)
+        valid = jnp.ones((1000,), jnp.bool_)
+        got = bucket_counts(keys, valid, 16, backend="interpret")
+        want = np.bincount(np.asarray(bucket_hash(keys, 16)), minlength=16)
+        assert np.array_equal(np.asarray(got), want)
 
 
 @pytest.mark.slow

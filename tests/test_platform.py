@@ -9,7 +9,7 @@ import sys
 import pytest
 
 from repro.config import (GPU_OVERLAP_FLAGS, _merge_xla_flags,
-                          configure_platform)
+                          configure_platform, enable_compilation_cache)
 
 
 def test_merge_replaces_same_name_and_keeps_others(monkeypatch):
@@ -58,3 +58,33 @@ def test_configure_platform_subprocess():
         capture_output=True, text=True, timeout=300, env=env)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "OK 16" in out.stdout
+
+
+@pytest.fixture
+def cache_dir_restored():
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    yield jax
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compilation_cache_honours_env(monkeypatch, cache_dir_restored,
+                                       tmp_path):
+    jax = cache_dir_restored
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compilation_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # set no other
+
+
+def test_compilation_cache_defaults_to_checkout(monkeypatch,
+                                                cache_dir_restored):
+    from pathlib import Path
+    jax = cache_dir_restored
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = Path(__file__).resolve().parents[1]
+    want = str(root / ".jax_cache")
+    assert enable_compilation_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    ignored = (root / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored, ".jax_cache must be git-ignored"
