@@ -251,7 +251,7 @@ def bench_overlap(repeats: int, rng, *, devices: int, chunks: int,
         # structure: shuffle every chunk, then join every chunk, each
         # join tied to all shuffles.
         def body(g, l, r):
-            left_s, _ = shuffle_to_device(g, _flat(l), "b", recv, 0, cap)
+            left_s = shuffle_to_device(g, _flat(l), "b", recv, 0, cap)[0]
             shuffled = [
                 shuffle_to_device(g, chunk, "b", recv, 0, cap)[0]
                 for chunk in split_rows(_flat(r), chunks)]
@@ -270,8 +270,8 @@ def bench_overlap(repeats: int, rng, *, devices: int, chunks: int,
 
     def shuffle_only():
         def body(g, l, r):
-            ls, _ = shuffle_to_device(g, _flat(l), "b", recv, 0, cap)
-            rs, _ = shuffle_to_device(g, _flat(r), "b", recv, 0, cap)
+            ls = shuffle_to_device(g, _flat(l), "b", recv, 0, cap)[0]
+            rs = shuffle_to_device(g, _flat(r), "b", recv, 0, cap)[0]
             return ls.count()[None], rs.count()[None]
         return jax.jit(lambda l, r: grid.run(
             body, l, r, in_specs=specs["in_specs"],

@@ -12,14 +12,16 @@ from __future__ import annotations
 
 from typing import Dict, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from . import hashing
 from .local import groupby_sum
 from .relation import Relation
-from .shuffle import Grid, shuffle_by_bucket
+from .shuffle import Grid, add_fill, buffer_fill, shuffle_by_bucket
 
 
+@jax.named_scope("join.groupby")
 def distributed_groupby_sum(grid: Grid, rel: Relation, keys: Sequence[str],
                             value: str, *, recv_capacity: int,
                             out_capacity: int, local_capacity: int | None = None,
@@ -43,11 +45,13 @@ def distributed_groupby_sum(grid: Grid, rel: Relation, keys: Sequence[str],
     overflow = jnp.zeros((), jnp.bool_)
 
     cur = rel
+    fills = []
     if local_combine:
         def combine(r: Relation):
             return groupby_sum(r, keys, value, backend=segment_backend)
         cur, ovf_c = grid.map_devices(combine, cur)
         overflow = overflow | jnp.any(grid.reduce_any(ovf_c))
+        fills.append(buffer_fill(grid, cur))
 
     def key_bucket(r: Relation, n_buckets: int, salt: int) -> jnp.ndarray:
         mixed = r.col(keys[0])
@@ -60,9 +64,11 @@ def distributed_groupby_sum(grid: Grid, rel: Relation, keys: Sequence[str],
             continue  # clamped axis: a single owner, the hop is a no-op
         bucket = grid.map_devices(
             lambda r, _a=axis: key_bucket(r, grid.shape[_a], salt=_a), cur)
-        cur, ovf, _ = shuffle_by_bucket(grid, cur, bucket, axis, recv_capacity,
-                                        local_capacity=local_capacity)
+        cur, ovf, fill = shuffle_by_bucket(grid, cur, bucket, axis,
+                                           recv_capacity,
+                                           local_capacity=local_capacity)
         overflow = overflow | ovf
+        fills.append(fill)
 
     shuffled = grid.reduce_sum(grid.map_devices(lambda r: r.count(), cur))
 
@@ -76,10 +82,12 @@ def distributed_groupby_sum(grid: Grid, rel: Relation, keys: Sequence[str],
     stats = {
         "read": n_in.astype(jnp.float32),
         "shuffled": shuffled.astype(jnp.float32),
+        **add_fill(*fills, buffer_fill(grid, agg)),
     }
     return agg, stats, overflow
 
 
+@jax.named_scope("join.groupby")
 def project_product(grid: Grid, rel: Relation, keys: Sequence[str],
                     value_cols: Sequence[str], out_name: str = "p") -> Relation:
     """Map phase of the aggregator: emit (keys, prod(value_cols)) —

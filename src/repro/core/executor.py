@@ -63,8 +63,9 @@ from .cost_model import ChainStats, chain_replications
 from .local import groupby_sum, local_join
 from .plan import ChainQuery, JoinQuery
 from .relation import Relation, concat
-from .shuffle import (Grid, SimGrid, broadcast_along, compact_to,
-                      concat_rows, shuffle_by_bucket, split_rows)
+from .shuffle import (FILL_KEYS, Grid, SimGrid, add_fill, broadcast_along,
+                      buffer_fill, compact_to, concat_rows, shuffle_by_bucket,
+                      split_rows)
 from .two_way import two_way_join
 
 Stats = Dict[str, jnp.ndarray]
@@ -134,6 +135,7 @@ def _join_steps(query: JoinQuery, order: Sequence[int]):
     return query.join_steps(order)
 
 
+@jax.named_scope("join.emit")
 def _close_cycle(acc: Relation, extras: Sequence[str]) -> Relation:
     """Apply the closing hop's extra equalities (`attr == _cc_attr`) and
     drop the renamed duplicates."""
@@ -147,10 +149,11 @@ def _close_cycle(acc: Relation, extras: Sequence[str]) -> Relation:
 
 def place_relation(grid: Grid, query: JoinQuery, j: int, rel: Relation, *,
                    caps: ChainCaps, measure_skew: bool = False,
-                   ) -> Tuple[Relation, jnp.ndarray, jnp.ndarray]:
+                   ) -> Tuple[Relation, jnp.ndarray, jnp.ndarray, Stats]:
     """The map/placement phase of one relation on the Shares hypercube:
     route to the pinned dims (one shuffle hop per hashed dim), replicate
-    over the rest.  Returns (placed shard, overflow, peak bucket load).
+    over the rest.  Returns (placed shard, overflow, peak bucket load,
+    the live-row counter of the placement's buffers).
 
     This is the per-relation *lineage unit* of a one-round join: a
     placement that dies (a lost map task) is recovered by re-running
@@ -161,6 +164,7 @@ def place_relation(grid: Grid, query: JoinQuery, j: int, rel: Relation, *,
     overflow = jnp.zeros((), jnp.bool_)
     skew = jnp.zeros((), jnp.float32)
     cur = rel
+    fills = []
     hashed = query.hashed_dims(j)
     for d in hashed:                     # route to the pinned dims
         if grid.shape[d] == 1:
@@ -172,15 +176,17 @@ def place_relation(grid: Grid, query: JoinQuery, j: int, rel: Relation, *,
         bucket = grid.map_devices(
             lambda r, _d=d, _a=attr: hashing.bucket_hash(
                 r.col(_a), grid.shape[_d], salt=_d), cur)
-        cur, ovf, _ = shuffle_by_bucket(grid, cur, bucket, d, caps.recv,
-                                        local_capacity=caps.local)
+        cur, ovf, fill = shuffle_by_bucket(grid, cur, bucket, d, caps.recv,
+                                           local_capacity=caps.local)
         overflow = overflow | ovf
+        fills.append(fill)
     for d in range(ndims):               # replicate over the rest
         if d in hashed or grid.shape[d] == 1:
             continue
-        cur, ovf = broadcast_along(grid, cur, d, caps.local)
+        cur, ovf, fill = broadcast_along(grid, cur, d, caps.local)
         overflow = overflow | ovf
-    return cur, overflow, skew
+        fills.append(fill)
+    return cur, overflow, skew, add_fill(*fills)
 
 
 def reduce_side_fn(query: JoinQuery, order: Sequence[int], *,
@@ -188,10 +194,11 @@ def reduce_side_fn(query: JoinQuery, order: Sequence[int], *,
     """Build the per-device reduce function of a one-round join: the
     left-deep chain of local joins along ``order``, cycle-closing
     filters applied at their hop.  Returns ``reduce(*shards) -> (acc,
-    overflow)`` — pure per-device work, so it can be vmapped over the
-    whole grid (the normal path) *or* run on one reducer coordinate's
-    shards alone (the failed-bucket re-execution path of
-    :func:`repro.resilience.recovery.resilient_one_round_query`)."""
+    overflow, outputs)``, ``outputs`` every local join's output buffer
+    (for the live-row counter) — pure per-device work, so it can be
+    vmapped over the whole grid (the normal path) *or* run on one
+    reducer coordinate's shards alone (the failed-bucket re-execution
+    path of :func:`repro.resilience.recovery.resilient_one_round_query`)."""
     n = query.n_relations
     order = tuple(order)
     steps = _join_steps(query, order)
@@ -201,6 +208,7 @@ def reduce_side_fn(query: JoinQuery, order: Sequence[int], *,
     def reduce_side(*shards: Relation):
         acc = shards[order[0]]
         ovf = jnp.zeros((), jnp.bool_)
+        outs = []
         for i, (j, key, extras) in enumerate(steps):
             right = shards[j]
             if extras:
@@ -210,7 +218,8 @@ def reduce_side_fn(query: JoinQuery, order: Sequence[int], *,
             ovf = ovf | o
             if extras:
                 acc = _close_cycle(acc, extras)
-        return acc, ovf
+            outs.append(acc)
+        return acc, ovf, tuple(outs)
 
     return reduce_side
 
@@ -219,8 +228,9 @@ def _reduce_split_fns(query: JoinQuery, order: Sequence[int], *,
                       caps: ChainCaps, join_impl: str = "sort_merge"):
     """:func:`reduce_side_fn` split at its last hop, for the overlapped
     one-round schedule: ``head`` runs the chain over every relation but
-    ``order[-1]`` (computed once), ``tail(acc, shard)`` applies the
-    final join + closing filters (run per placement chunk).  Returns
+    ``order[-1]`` (computed once) and also returns its local joins'
+    output buffers, ``tail(acc, shard)`` applies the final join +
+    closing filters (run per placement chunk).  Returns
     ``(js_head, head, tail, final_cap)`` where ``js_head`` lists the
     relation indices ``head`` consumes, in ascending order."""
     n = query.n_relations
@@ -235,6 +245,7 @@ def _reduce_split_fns(query: JoinQuery, order: Sequence[int], *,
         sh = dict(zip(js_head, shards))
         acc = sh[order[0]]
         ovf = jnp.zeros((), jnp.bool_)
+        outs = []
         for i, (j, key, extras) in enumerate(steps[:-1]):
             right = sh[j]
             if extras:
@@ -244,7 +255,8 @@ def _reduce_split_fns(query: JoinQuery, order: Sequence[int], *,
             ovf = ovf | o
             if extras:
                 acc = _close_cycle(acc, extras)
-        return acc, ovf
+            outs.append(acc)
+        return acc, ovf, tuple(outs)
 
     _, key_l, extras_l = steps[-1]
 
@@ -300,21 +312,25 @@ def one_round_query(grid: Grid, query: JoinQuery, rels: Sequence[Relation], *,
     order = tuple(join_order) if join_order is not None \
         else query.default_join_order()
 
+    fills: List[Stats] = []
     if overlap_chunks <= 1 or n < 2:
         placed: List[Relation] = []
         for j, rel in enumerate(rels):
-            cur, ovf, sk = place_relation(grid, query, j, rel, caps=caps,
-                                          measure_skew=measure_skew)
+            cur, ovf, sk, fill = place_relation(grid, query, j, rel,
+                                                caps=caps,
+                                                measure_skew=measure_skew)
             overflow = overflow | ovf
             skew = jnp.maximum(skew, sk)
             placed.append(cur)
+            fills.append(fill)
 
         # Reduce side: left-deep chain of local joins (pure per-device
         # work).
         reduce_side = reduce_side_fn(query, order, caps=caps,
                                      join_impl=join_impl)
-        joined, ovf_j = grid.map_devices(reduce_side, *placed)
+        joined, ovf_j, outs = grid.map_devices(reduce_side, *placed)
         overflow = overflow | jnp.any(grid.reduce_any(ovf_j))
+        fills.append(buffer_fill(grid, *outs))
 
         # Measured shuffle = tuples resident at reducers after placement
         # (each relation counted with its replication factor).
@@ -332,11 +348,13 @@ def one_round_query(grid: Grid, query: JoinQuery, rels: Sequence[Relation], *,
         last = order[-1]
         placed_head: Dict[int, Relation] = {}
         for j in js_head:
-            cur, ovf, sk = place_relation(grid, query, j, rels[j], caps=caps,
-                                          measure_skew=measure_skew)
+            cur, ovf, sk, fill = place_relation(grid, query, j, rels[j],
+                                                caps=caps,
+                                                measure_skew=measure_skew)
             overflow = overflow | ovf
             skew = jnp.maximum(skew, sk)
             placed_head[j] = cur
+            fills.append(fill)
         if measure_skew:
             # The last relation's hop histograms, measured on the full
             # input (identical to the staged measurement — chunk
@@ -348,28 +366,32 @@ def one_round_query(grid: Grid, query: JoinQuery, rels: Sequence[Relation], *,
                     grid, rels[last], query.dim_attr(d), grid.shape[d],
                     salt=d))
 
-        acc, ovf_h = grid.map_devices(head, *[placed_head[j]
-                                              for j in js_head])
+        acc, ovf_h, outs = grid.map_devices(head, *[placed_head[j]
+                                                    for j in js_head])
         overflow = overflow | jnp.any(grid.reduce_any(ovf_h))
         received = sum(_count(grid, p) for p in placed_head.values())
+        fills.append(buffer_fill(grid, *outs))
 
         parts: List[Relation] = []
         for chunk in split_rows(rels[last], overlap_chunks):
-            pc, ovf_c, _ = place_relation(grid, query, last, chunk,
-                                          caps=caps, measure_skew=False)
+            pc, ovf_c, _, fill = place_relation(grid, query, last, chunk,
+                                                caps=caps, measure_skew=False)
             received = received + _count(grid, pc)
             out_c, ovf_t = grid.map_devices(tail, acc, pc)
             overflow = overflow | ovf_c | jnp.any(grid.reduce_any(ovf_t))
             parts.append(out_c)
+            fills.append(fill)
         # Chunk matches are subsets of the staged hop's, so the chunk
         # joins at final_cap cannot overflow unless the staged join
         # would; the compaction reimposes the staged capacity and its
         # overflow condition.
         joined, ovf_cc = compact_to(grid, concat_rows(parts), final_cap)
         overflow = overflow | ovf_cc
+        fills.append(buffer_fill(grid, *parts, joined))
     stats: Stats = {
         "read": read.astype(jnp.float32),
         "shuffled": received.astype(jnp.float32),
+        **add_fill(*fills),
     }
     if measure_skew:
         stats["max_bucket_load"] = skew
@@ -587,6 +609,8 @@ def cascade_chain(grid: Grid, query: ChainQuery, rels: Sequence[Relation], *,
         overflow = overflow | ovf_f
         if include_final_agg or not pushdown:
             all_stats.append(st_f)
+        else:                   # uncharged, but its buffers are filled
+            all_stats.append({k: st_f[k] for k in FILL_KEYS})
 
     stats = merge_stats(*all_stats)
     if measure_skew:
@@ -718,8 +742,9 @@ def mapside_cascade_chain(grid: Grid, query: ChainQuery, rels, *,
         else:
             read = (_count(grid, left) + _count(grid, right)
                     ).astype(jnp.float32)
+            fill = buffer_fill(grid)
             if mode == "broadcast":
-                right, ovf_b = broadcast_along(grid, right, 0, local)
+                right, ovf_b, fill = broadcast_along(grid, right, 0, local)
                 overflow = overflow | ovf_b
                 shuffled = _count(grid, right).astype(jnp.float32)
                 pre_l, pre_r = False, False   # the gather interleaves runs
@@ -734,7 +759,7 @@ def mapside_cascade_chain(grid: Grid, query: ChainQuery, rels, *,
                     bucket = grid.map_devices(
                         lambda r, _a=key: hashing.bucket_hash(
                             r.col(_a), P, salt=partitioning.salt), left)
-                    left, ovf_s, _ = shuffle_by_bucket(
+                    left, ovf_s, fill = shuffle_by_bucket(
                         grid, left, bucket, 0, recv, local_capacity=local)
                     overflow = overflow | ovf_s
                     shuffled = _count(grid, left).astype(jnp.float32)
@@ -747,7 +772,8 @@ def mapside_cascade_chain(grid: Grid, query: ChainQuery, rels, *,
 
             left, ovf_j = grid.map_devices(hop, left, right)
             overflow = overflow | jnp.any(grid.reduce_any(ovf_j))
-            all_stats.append({"read": read, "shuffled": shuffled})
+            all_stats.append({"read": read, "shuffled": shuffled,
+                              **add_fill(fill, buffer_fill(grid, left))})
             hop_shuffled.append(shuffled)
 
         left_sorted = False
@@ -763,10 +789,11 @@ def mapside_cascade_chain(grid: Grid, query: ChainQuery, rels, *,
             # the same slack fits in out_cap/P-sized slots — placement
             # buffers stay a fraction of a shuffle hop's.
             slot = -(-out_cap // P) + 256
-            left, ovf_p, _ = shuffle_by_bucket(grid, left, bucket, 0,
-                                               slot,
-                                               local_capacity=out_cap)
+            left, ovf_p, fill = shuffle_by_bucket(grid, left, bucket, 0,
+                                                  slot,
+                                                  local_capacity=out_cap)
             overflow = overflow | ovf_p
+            all_stats.append(fill)
             hop_placed.append(_count(grid, left).astype(jnp.float32))
             left_on_key = True
         else:
@@ -870,7 +897,8 @@ def shares_skew_chain(query: ChainQuery, rels: Sequence[Relation], plan, *,
     query.check_relations(rels)
     if not plan.combos:
         zero = jnp.zeros((), jnp.float32)
-        stats: Stats = {"read": zero, "shuffled": zero, "total": zero}
+        stats: Stats = {"read": zero, "shuffled": zero, "total": zero,
+                        **{k: zero for k in FILL_KEYS}}
         if measure_skew:
             stats["max_bucket_load"] = zero
         # Key dtypes come from the actual input columns so an empty
@@ -914,6 +942,7 @@ def shares_skew_chain(query: ChainQuery, rels: Sequence[Relation], plan, *,
         agg = query.aggregate
         result, ovf_m = groupby_sum(result, tuple(agg.keys), agg.out)
         overflow = overflow | ovf_m
+        all_stats.append(buffer_fill(SimGrid(()), result))
     return result, merge_stats(*all_stats), overflow
 
 

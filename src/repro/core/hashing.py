@@ -9,6 +9,7 @@ edge-list relations.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 # Plain Python ints (NOT jnp arrays): module-level jnp constants would
@@ -18,6 +19,7 @@ _KNUTH = 2654435761  # 2^32 / phi
 _SALTS = (0x9E3779B9, 0x85EBCA6B, 0xC2B2AE35, 0x27D4EB2F)
 
 
+@jax.named_scope("join.partition")
 def bucket_hash(x: jnp.ndarray, n_buckets: int, salt: int = 0) -> jnp.ndarray:
     """Hash int keys into [0, n_buckets) with a salted multiplicative hash.
 

@@ -39,6 +39,7 @@ _I32_MAX = jnp.iinfo(jnp.int32).max
 # Hash partition (map-phase counting sort into destination buckets)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("join.partition")
 def partition_ranks(bucket: jnp.ndarray, valid: jnp.ndarray, n_buckets: int
                     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Stable counting-sort plan: for each element, its destination bucket
@@ -64,6 +65,7 @@ def partition_ranks(bucket: jnp.ndarray, valid: jnp.ndarray, n_buckets: int
     return order, sorted_key, rank
 
 
+@jax.named_scope("join.partition")
 def partition(rel: Relation, bucket: jnp.ndarray, n_buckets: int,
               cap_per_bucket: int) -> Tuple[Relation, jnp.ndarray]:
     """Scatter tuples into (n_buckets, cap_per_bucket) send buffers.
@@ -98,6 +100,7 @@ def partition(rel: Relation, bucket: jnp.ndarray, n_buckets: int,
 # Local equi-join (the reduce-side join within one reducer)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("join.emit")
 def _emit_join_columns(left: Relation, right: Relation, left_key: str,
                        right_key: str, li_out: jnp.ndarray,
                        ri_out: jnp.ndarray, valid_out: jnp.ndarray,
@@ -127,6 +130,7 @@ def _key_sentinel(dtype) -> int:
         else _I32_MAX
 
 
+@jax.named_scope("join.sort")
 def _sorted_by_key(key: jnp.ndarray, valid: jnp.ndarray,
                    presorted: bool = False
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -156,6 +160,7 @@ def _sorted_by_key(key: jnp.ndarray, valid: jnp.ndarray,
     return order, masked
 
 
+@jax.named_scope("join.sort")
 def sort_rows(rel: Relation, key: str) -> Relation:
     """Reorder a relation into the sorted-rows contract: valid rows
     first, ascending ``key`` (stable).  This is the layout
@@ -165,6 +170,7 @@ def sort_rows(rel: Relation, key: str) -> Relation:
     return rel.gather(order, jnp.ones(rel.valid.shape, jnp.bool_))
 
 
+@jax.named_scope("join.emit")
 def _probe_expand_emit(left: Relation, right: Relation, left_key: str,
                        right_key: str, out_capacity: int, prefix_l: str,
                        prefix_r: str, n_lv: jnp.ndarray, n_rv: jnp.ndarray,
@@ -264,8 +270,9 @@ def sort_merge_join(left: Relation, right: Relation, left_key: str,
 
     # Run-length probe: matches of sorted-left row i live in
     # right-sorted positions [lo[i], hi[i]).
-    lo = jnp.searchsorted(rk_m, lk_m, side="left")
-    hi = jnp.searchsorted(rk_m, lk_m, side="right")
+    with jax.named_scope("join.probe"):
+        lo = jnp.searchsorted(rk_m, lk_m, side="left")
+        hi = jnp.searchsorted(rk_m, lk_m, side="right")
     return _probe_expand_emit(left, right, left_key, right_key, out_capacity,
                               prefix_l, prefix_r, n_lv, n_rv,
                               l_order, r_order, lo, hi)
@@ -316,6 +323,7 @@ def fused_sort_merge_join(left: Relation, right: Relation, left_key: str,
                               l_order, r_order, lo, hi)
 
 
+@jax.named_scope("join.probe")
 def local_join_allpairs(left: Relation, right: Relation, left_key: str,
                         right_key: str, out_capacity: int,
                         prefix_l: str = "", prefix_r: str = "",
@@ -410,6 +418,7 @@ def _group_heads(sorted_valid: jnp.ndarray, sorted_keys) -> Tuple[jnp.ndarray,
     return head, seg_id
 
 
+@jax.named_scope("join.groupby")
 def groupby_sum(rel: Relation, keys: Tuple[str, ...], value: str,
                 out_capacity: int | None = None, *, backend: str = "auto",
                 ) -> Tuple[Relation, jnp.ndarray]:
@@ -458,6 +467,7 @@ def groupby_sum(rel: Relation, keys: Tuple[str, ...], value: str,
     return Relation(out_cols, valid_out), overflow
 
 
+@jax.named_scope("join.groupby")
 def groupby_sum_multipass(rel: Relation, keys: Tuple[str, ...], value: str,
                           out_capacity: int | None = None
                           ) -> Tuple[Relation, jnp.ndarray]:

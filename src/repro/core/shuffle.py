@@ -19,7 +19,8 @@ tests/test_shuffle_equivalence.py on a multi-device CPU subprocess.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Sequence, Tuple
+import math
+from typing import Any, Callable, Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,12 +72,14 @@ class SimGrid(Grid):
             f = jax.vmap(f)
         return f(*args)
 
+    @jax.named_scope("join.shuffle")
     def all_to_all(self, x, grid_axis: int):
         # global x: (*grid, K_dest, ...) -> swap grid axis with bucket axis.
         def swap(a):
             return jnp.swapaxes(a, grid_axis, self.ndim)
         return jax.tree.map(swap, x)
 
+    @jax.named_scope("join.shuffle")
     def all_gather(self, x, grid_axis: int):
         # global x: (*grid, ...) -> (*grid, K_src, ...) with
         # out[g0..gn-1, s, ...] = x[g with coordinate grid_axis replaced by s]
@@ -92,9 +95,11 @@ class SimGrid(Grid):
             return jnp.broadcast_to(expanded, tuple(shape))
         return jax.tree.map(gather, x)
 
+    @jax.named_scope("join.shuffle")
     def reduce_any(self, x):
         return jax.tree.map(lambda a: jnp.any(a, axis=tuple(range(self.ndim))), x)
 
+    @jax.named_scope("join.shuffle")
     def reduce_sum(self, x):
         return jax.tree.map(lambda a: jnp.sum(a, axis=tuple(range(self.ndim))), x)
 
@@ -120,12 +125,14 @@ class ShardGrid(Grid):
     def map_devices(self, fn, *args):
         return fn(*args)  # shard_map body is already per-device
 
+    @jax.named_scope("join.shuffle")
     def all_to_all(self, x, grid_axis: int):
         name = self.axis_names[grid_axis]
         return jax.tree.map(
             lambda a: jax.lax.all_to_all(a, name, split_axis=0, concat_axis=0,
                                          tiled=False), x)
 
+    @jax.named_scope("join.shuffle")
     def all_gather(self, x, grid_axis: int):
         name = self.axis_names[grid_axis]
         return jax.tree.map(
@@ -138,10 +145,12 @@ class ShardGrid(Grid):
             out.extend([a] if isinstance(a, str) else list(a))
         return tuple(out)
 
+    @jax.named_scope("join.shuffle")
     def reduce_any(self, x):
         return jax.tree.map(
             lambda a: jax.lax.psum(a.astype(jnp.int32), self._flat_axes) > 0, x)
 
+    @jax.named_scope("join.shuffle")
     def reduce_sum(self, x):
         return jax.tree.map(lambda a: jax.lax.psum(a, self._flat_axes), x)
 
@@ -184,9 +193,40 @@ def _inject(site: str, payload):
 
 
 # ---------------------------------------------------------------------------
+# The live-row counter
+# ---------------------------------------------------------------------------
+
+#: Stats keys of the live-row counter: valid rows, and static capacity
+#: in rows, summed over every buffer a query fills (the receive and
+#: compacted buffers of each shuffle hop or broadcast, each local
+#: join's output, each group-by's output), over the whole grid.
+FILL_KEYS = ("live_rows", "buffer_rows")
+
+
+def buffer_fill(grid: Grid, *rels: Relation) -> Dict[str, jnp.ndarray]:
+    """The live-row counter of the grid-level buffers ``rels``, counted
+    where they are produced: their valid rows over the grid and their
+    capacity in rows.  Stats units, so ``merge_stats`` sums them
+    across rounds."""
+    if not rels:
+        return {k: jnp.zeros((), jnp.float32) for k in FILL_KEYS}
+    n_dev = math.prod(grid.shape)
+    per_dev = sum(grid.map_devices(lambda r: r.count(), r) for r in rels)
+    return {"live_rows": grid.reduce_sum(per_dev).astype(jnp.float32),
+            "buffer_rows": jnp.float32(
+                n_dev * sum(r.capacity for r in rels))}
+
+
+def add_fill(*fills: Dict[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+    """Sum live-row counters."""
+    return {k: sum(f[k] for f in fills) for k in FILL_KEYS}
+
+
+# ---------------------------------------------------------------------------
 # Distributed shuffle: the MapReduce sort/shuffle guarantee
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("join.shuffle")
 def compact_to(grid: Grid, rel: Relation, capacity: int):
     """Per-device: move valid rows to the front and shrink the buffer to
     ``capacity`` (the reducer's memory budget).  Returns (rel, overflow)."""
@@ -199,6 +239,7 @@ def compact_to(grid: Grid, rel: Relation, capacity: int):
     return out, jnp.any(grid.reduce_any(ovf))
 
 
+@jax.named_scope("join.shuffle")
 def shuffle_by_bucket(grid: Grid, rel: Relation, bucket, grid_axis: int,
                       recv_capacity: int, local_capacity: int | None = None):
     """Move every tuple to the device whose index along ``grid_axis``
@@ -208,23 +249,25 @@ def shuffle_by_bucket(grid: Grid, rel: Relation, bucket, grid_axis: int,
     [0, shape[grid_axis])).  ``recv_capacity`` is per (device, source)
     slot capacity.  The received K×recv buffers are compacted to
     ``local_capacity`` (defaults to K·recv = lossless).  Returns
-    (local Relation, overflow flag (global), tuples_sent per device).
+    (local Relation, overflow flag (global), the live-row counter of the
+    receive buffer and, where compacted, the local buffer).
     """
     K = grid.shape[grid_axis]
 
     def send(r: Relation, b):
-        buf, ovf = partition(r, b, K, recv_capacity)
-        return buf, ovf, r.count()
+        return partition(r, b, K, recv_capacity)
 
-    buf, ovf, n_sent = grid.map_devices(send, rel, bucket)
+    buf, ovf = grid.map_devices(send, rel, bucket)
     recv = grid.all_to_all(buf, grid_axis)
     recv = _inject("shuffle", recv)
     local = grid.map_devices(flatten_leading, recv)
+    filled = [local]
     overflow = jnp.any(grid.reduce_any(ovf))
     if local_capacity is not None and local_capacity < K * recv_capacity:
         local, ovf_c = compact_to(grid, local, local_capacity)
         overflow = overflow | ovf_c
-    return local, overflow, n_sent
+        filled.append(local)
+    return local, overflow, buffer_fill(grid, *filled)
 
 
 # ---------------------------------------------------------------------------
@@ -269,6 +312,7 @@ def concat_rows(rels) -> Relation:
     return Relation(cols, valid)
 
 
+@jax.named_scope("join.shuffle")
 def broadcast_along(grid: Grid, rel: Relation, grid_axis: int,
                     local_capacity: int | None = None):
     """Replicate a per-device relation along a grid axis (the 1,3J
@@ -276,11 +320,13 @@ def broadcast_along(grid: Grid, rel: Relation, grid_axis: int,
     concatenation of all shards along that axis; the per-device tuple
     count multiplies by shape[grid_axis] — exactly the k·|rel|
     communication cost the paper charges.  Optionally compacts the
-    result to ``local_capacity``."""
+    result to ``local_capacity``.  Returns (rel, overflow, the live-row
+    counter of the gathered buffer and, where compacted, the local
+    buffer)."""
     gathered = grid.all_gather(rel, grid_axis)
     gathered = _inject("shuffle", gathered)
     out = grid.map_devices(flatten_leading, gathered)
     if local_capacity is not None:
-        out, ovf = compact_to(grid, out, local_capacity)
-        return out, ovf
-    return out, jnp.zeros((), jnp.bool_)
+        local, ovf = compact_to(grid, out, local_capacity)
+        return local, ovf, buffer_fill(grid, out, local)
+    return out, jnp.zeros((), jnp.bool_), buffer_fill(grid, out)

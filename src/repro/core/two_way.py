@@ -18,8 +18,8 @@ import jax.numpy as jnp
 from . import hashing
 from .local import local_join
 from .relation import Relation
-from .shuffle import (Grid, compact_to, concat_rows, shuffle_by_bucket,
-                      split_rows)
+from .shuffle import (Grid, add_fill, buffer_fill, compact_to, concat_rows,
+                      shuffle_by_bucket, split_rows)
 
 
 def flat_grid_bucket(grid: Grid, key: jnp.ndarray, salt: int = 0) -> Tuple[jnp.ndarray, ...]:
@@ -42,18 +42,22 @@ def shuffle_to_device(grid: Grid, rel: Relation, key: str, recv_capacity: int,
     """Route every tuple to the unique device owning hash(key) — one hop per
     grid axis (multi-hop routing on >1-D grids, same final guarantee).
     After each hop the receive buffers are compacted to
-    ``local_capacity`` (the reducer memory budget)."""
+    ``local_capacity`` (the reducer memory budget).  Returns (rel,
+    overflow, the live-row counter of every hop's buffers)."""
     overflow = jnp.zeros((), jnp.bool_)
     cur = rel
+    fills = []
     for axis in range(len(grid.shape)):
         def bucketize(r: Relation, _axis=axis):
             return flat_grid_bucket(grid, r.col(key), salt=salt)[_axis]
 
         bucket = grid.map_devices(bucketize, cur)
-        cur, ovf, _ = shuffle_by_bucket(grid, cur, bucket, axis, recv_capacity,
-                                        local_capacity=local_capacity)
+        cur, ovf, fill = shuffle_by_bucket(grid, cur, bucket, axis,
+                                           recv_capacity,
+                                           local_capacity=local_capacity)
         overflow = overflow | ovf
-    return cur, overflow
+        fills.append(fill)
+    return cur, overflow, add_fill(*fills)
 
 
 def two_way_join(grid: Grid, left: Relation, right: Relation,
@@ -68,7 +72,9 @@ def two_way_join(grid: Grid, left: Relation, right: Relation,
 
     Returns (per-device join shards, stats, overflow).  stats counts
     tuples in the paper's units: ``read`` (map input) and ``shuffled``
-    (map output received by reducers) — cost of this round is their sum.
+    (map output received by reducers) — cost of this round is their sum
+    — and the live-row counter of the round's buffers (``live_rows``,
+    ``buffer_rows``).
 
     ``join_impl`` selects the reduce-side kernel: ``"sort_merge"``
     (default, the sorted-probe fast path), ``"fused"`` (the rank-packed
@@ -88,8 +94,9 @@ def two_way_join(grid: Grid, left: Relation, right: Relation,
     n_left = grid.reduce_sum(grid.map_devices(lambda r: r.count(), left))
     n_right = grid.reduce_sum(grid.map_devices(lambda r: r.count(), right))
 
-    left_s, ovf_l = shuffle_to_device(grid, left, left_key, recv_capacity,
-                                      salt, local_capacity)
+    left_s, ovf_l, fill = shuffle_to_device(grid, left, left_key,
+                                            recv_capacity, salt,
+                                            local_capacity)
 
     def reduce_side(l: Relation, r: Relation):
         return local_join(l, r, left_key, right_key, out_capacity,
@@ -100,31 +107,35 @@ def two_way_join(grid: Grid, left: Relation, right: Relation,
         return grid.reduce_sum(grid.map_devices(lambda r: r.count(), rel))
 
     if overlap_chunks <= 1:
-        right_s, ovf_r = shuffle_to_device(grid, right, right_key,
-                                           recv_capacity, salt, local_capacity)
+        right_s, ovf_r, fill_r = shuffle_to_device(grid, right, right_key,
+                                                   recv_capacity, salt,
+                                                   local_capacity)
         joined, ovf_j = grid.map_devices(reduce_side, left_s, right_s)
         overflow = ovf_l | ovf_r | jnp.any(grid.reduce_any(ovf_j))
         received = shard_count(left_s) + shard_count(right_s)
+        fill = add_fill(fill, fill_r, buffer_fill(grid, joined))
     else:
         overflow = ovf_l
         received = shard_count(left_s)
         parts = []
         for chunk in split_rows(right, overlap_chunks):
-            chunk_s, ovf_c = shuffle_to_device(grid, chunk, right_key,
-                                               recv_capacity, salt,
-                                               local_capacity)
+            chunk_s, ovf_c, fill_c = shuffle_to_device(
+                grid, chunk, right_key, recv_capacity, salt, local_capacity)
             received = received + shard_count(chunk_s)
             out_c, ovf_j = grid.map_devices(reduce_side, left_s, chunk_s)
             overflow = overflow | ovf_c | jnp.any(grid.reduce_any(ovf_j))
             parts.append(out_c)
+            fill = add_fill(fill, fill_c)
         # Per-chunk matches are a subset of the full hop's, so the chunk
         # joins at out_capacity cannot overflow unless the staged hop
         # would; the final compaction reimposes the staged capacity and
         # its overflow condition (total matches > out_capacity).
         joined, ovf_cc = compact_to(grid, concat_rows(parts), out_capacity)
         overflow = overflow | ovf_cc
+        fill = add_fill(fill, buffer_fill(grid, *parts, joined))
     stats = {
         "read": (n_left + n_right).astype(jnp.float32),
         "shuffled": received.astype(jnp.float32),
+        **fill,
     }
     return joined, stats, overflow

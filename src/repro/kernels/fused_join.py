@@ -92,6 +92,7 @@ def _packed_stable_argsort(rank: jnp.ndarray, n_ranks: int) -> Optional[jnp.ndar
     return (jnp.sort(packed) % jnp.asarray(max(n, 1), dt)).astype(jnp.int32)
 
 
+@jax.named_scope("join.sort")
 def stable_key_order(key: jnp.ndarray, valid: jnp.ndarray
                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Stable sort order by (validity, key) — bit-identical to
@@ -232,6 +233,7 @@ def probe_counts_pallas(queries: jnp.ndarray, sorted_keys: jnp.ndarray, *,
     return lo, hi
 
 
+@jax.named_scope("join.probe")
 def probe_counts(queries: jnp.ndarray, sorted_keys: jnp.ndarray, *,
                  backend: str = "auto", block_q: int = 512,
                  block_r: int = 512) -> Tuple[jnp.ndarray, jnp.ndarray]:

@@ -107,6 +107,7 @@ def hash_histogram(keys: jnp.ndarray, valid: jnp.ndarray, n_buckets: int, *,
     return out.reshape(n_blocks, k_pad)[:, :n_buckets]
 
 
+@jax.named_scope("join.partition")
 def bucket_counts(keys: jnp.ndarray, valid: jnp.ndarray, n_buckets: int, *,
                   salt: int = 0, block: int = 1024,
                   backend: str = "auto") -> jnp.ndarray:

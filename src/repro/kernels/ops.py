@@ -3,6 +3,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from . import backend as _backend
@@ -12,6 +13,7 @@ from . import ref
 from . import segment_sum as _ss
 
 
+@jax.named_scope("join.groupby")
 def segment_sum(values, segment_ids, num_segments: int,
                 backend: str = "auto", *,
                 indices_are_sorted: bool = False) -> jnp.ndarray:
@@ -24,6 +26,7 @@ def segment_sum(values, segment_ids, num_segments: int,
                            interpret=(b == "interpret"))
 
 
+@jax.named_scope("join.partition")
 def hash_histogram(keys, valid, n_buckets: int, *, salt: int = 0,
                    block: int = 1024, backend: str = "auto") -> jnp.ndarray:
     b = _backend.resolve(backend)

@@ -45,7 +45,7 @@ from ..core.executor import (ChainCaps, _close_cycle, _count, merge_stats,
                              place_relation, reduce_side_fn)
 from ..core.plan import JoinQuery
 from ..core.relation import Relation
-from ..core.shuffle import Grid
+from ..core.shuffle import Grid, add_fill, buffer_fill
 from ..core.two_way import two_way_join
 from ..core.aggregation import distributed_groupby_sum, project_product
 from . import faults
@@ -374,17 +374,19 @@ def resilient_one_round_query(grid: Grid, query: JoinQuery,
     overflow = jnp.zeros((), jnp.bool_)
 
     placed: List[Relation] = []
+    fills: List[Stats] = []
     for j, rel in enumerate(rels):
         def attempt(j=j, rel=rel):
             return place_relation(grid, query, j, rel, caps=caps)
 
         n_in = float(_count(grid, rel))
-        cur, ovf, _ = _retry(
+        cur, ovf, _, fill = _retry(
             policy, f"placement_{j}", attempt, report,
             charge=lambda out, n_in=n_in: (n_in,
                                            float(_count(grid, out[0]))))
         overflow = overflow | ovf
         placed.append(cur)
+        fills.append(fill)
 
     order = tuple(join_order) if join_order is not None \
         else query.default_join_order()
@@ -392,7 +394,9 @@ def resilient_one_round_query(grid: Grid, query: JoinQuery,
                                  join_impl=join_impl)
 
     # Optimistic full reduce pass, then seeded per-reducer failures.
-    joined, ovf_j = grid.map_devices(reduce_side, *placed)
+    joined, ovf_j, outs = grid.map_devices(reduce_side, *placed)
+    # a retried reducer re-runs on the same shards: the same buffers
+    fills.append(buffer_fill(grid, *outs))
     failed: List[Tuple[int, ...]] = []
     for coord in itertools.product(*[range(s) for s in grid.shape]):
         try:
@@ -407,7 +411,7 @@ def resilient_one_round_query(grid: Grid, query: JoinQuery,
         def attempt(shards=shards):
             return reduce_side(*shards)
 
-        acc, ovf_c = _retry(
+        acc, ovf_c, _ = _retry(
             policy, f"reducer_{coord}", attempt, report,
             charge=lambda out, r=resident: (r, 0.0))
         # The failed bucket re-read its resident shards once even on a
@@ -423,6 +427,7 @@ def resilient_one_round_query(grid: Grid, query: JoinQuery,
     stats: Stats = {
         "read": read.astype(jnp.float32),
         "shuffled": received.astype(jnp.float32),
+        **add_fill(*fills),
     }
 
     if query.aggregate is None:

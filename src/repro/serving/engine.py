@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import time
 from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple,
                     Union)
@@ -426,6 +427,8 @@ class QueryEngine:
         self._batched: "collections.OrderedDict[Any, Any]" = \
             collections.OrderedDict()
         self.stats = ServingStats()
+        # The id each request's trace spans carry (``query=<n>``).
+        self._query_ids = itertools.count()
         # Admission-control state: consecutive build failures (circuit
         # breaker), fast-failures since it opened (half-open probing),
         # and the SLO probe counter (shed trickle).
@@ -627,7 +630,8 @@ class QueryEngine:
                           run=run, chain_exec=True, exec_opts=opts,
                           report=report, degraded=degraded)
 
-    def _resolve(self, req: QueryRequest) -> Tuple[Tuple, CachedPlan, bool]:
+    def _resolve(self, req: QueryRequest,
+                 qid: int = 0) -> Tuple[Tuple, CachedPlan, bool]:
         stats = req.stats
         if stats is None:
             arities = [len(r) for r in req.query.relations]
@@ -645,8 +649,9 @@ class QueryEngine:
                 f"{self._breaker_failures} consecutive build failures; "
                 f"cache misses fail fast (hits still serve)")
         try:
-            entry = self._build_entry(dataclasses.replace(req, stats=stats),
-                                      stats)
+            with jax.profiler.TraceAnnotation("engine.build", query=qid):
+                entry = self._build_entry(
+                    dataclasses.replace(req, stats=stats), stats)
         except Exception:
             self._breaker_failures += 1
             raise
@@ -787,6 +792,7 @@ class QueryEngine:
         admitted = 0
         for i, req in enumerate(requests):
             t0 = time.perf_counter()
+            qid = next(self._query_ids)
             deadline = req.deadline_ms if req.deadline_ms is not None \
                 else self.cfg.deadline_ms
             # Admission control: queue bound, then the latency SLO.
@@ -809,11 +815,14 @@ class QueryEngine:
                 results[i] = self._reject(t0, "fault", e)
                 continue
             try:
-                key, entry, hit = self._resolve(req)
-                if prebuilt is not None and prebuilt[i] is not None:
-                    rels = self._adapt_prebuilt(tuple(prebuilt[i]), entry)
-                else:
-                    rels = self._prep_inputs(req, entry.grid_shape)
+                with jax.profiler.TraceAnnotation("engine.resolve", query=qid):
+                    key, entry, hit = self._resolve(req, qid)
+                with jax.profiler.TraceAnnotation("engine.prep", query=qid):
+                    if prebuilt is not None and prebuilt[i] is not None:
+                        rels = self._adapt_prebuilt(tuple(prebuilt[i]),
+                                                    entry)
+                    else:
+                        rels = self._prep_inputs(req, entry.grid_shape)
             except CircuitOpen as e:
                 results[i] = self._reject(t0, "circuit", e)
                 continue
@@ -833,7 +842,7 @@ class QueryEngine:
             admitted += 1
             gkey = (id(entry.run), self._shape_sig(rels))
             groups.setdefault(gkey, []).append(
-                (i, hit, entry, rels, t0, deadline, key))
+                (i, hit, entry, rels, t0, deadline, key, qid))
 
         for members in groups.values():
             self._run_group(members, results)
@@ -873,7 +882,7 @@ class QueryEngine:
             # poisoned entries, fail the group's lanes with a typed
             # error, and feed the circuit breaker.
             self._breaker_failures += 1
-            for (i, hit, entry, rels, t0, deadline, key) in members:
+            for (i, hit, entry, rels, t0, deadline, key, _) in members:
                 self._cache.pop(key, None)
                 self.stats.errors += 1
                 self.stats.queries += 1
@@ -889,15 +898,17 @@ class QueryEngine:
         # cache hit says nothing about build health and leaves it.
         fresh = any(not m[1] for m in members)
         if len(members) == 1:
-            i, hit, entry, rels, t0, deadline, _key = members[0]
-            out, st, ovf = entry.run(rels)
-            jax.block_until_ready(out.valid)
+            i, hit, entry, rels, t0, deadline, _key, qid = members[0]
+            with jax.profiler.TraceAnnotation("engine.run", query=qid):
+                out, st, ovf = entry.run(rels)
+                jax.block_until_ready(out.valid)
             if fresh:
                 self._breaker_failures = 0
                 self._breaker_fastfails = 0
             dt = (time.perf_counter() - t0) * 1e3
-            results[i] = self._lane_result(entry, out, st, ovf, hit, dt,
-                                           deadline)
+            with jax.profiler.TraceAnnotation("engine.result", query=qid):
+                results[i] = self._lane_result(entry, out, st, ovf, hit, dt,
+                                               deadline)
             self.stats.queries += 1
             self.stats.latencies_ms.append(dt)
             return
@@ -905,28 +916,33 @@ class QueryEngine:
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs),
                                *[m[3] for m in members])
         t0 = min(m[4] for m in members)
-        outs, sts, ovfs = batched(stacked)
-        jax.block_until_ready(outs.valid)
+        with jax.profiler.TraceAnnotation("engine.run", query=members[0][7],
+                                          lanes=len(members)):
+            outs, sts, ovfs = batched(stacked)
+            jax.block_until_ready(outs.valid)
         if fresh:
             self._breaker_failures = 0
             self._breaker_fastfails = 0
         dt = (time.perf_counter() - t0) * 1e3
-        for lane, (i, hit, entry, rels, _, deadline, _key) \
+        for lane, (i, hit, entry, rels, _, deadline, _key, qid) \
                 in enumerate(members):
             out = jax.tree.map(lambda x, lane=lane: x[lane], outs)
             st = {k: v[lane] for k, v in sts.items()}
-            results[i] = self._lane_result(entry, out, st, ovfs[lane], hit,
-                                           dt, deadline)
+            with jax.profiler.TraceAnnotation("engine.result", query=qid):
+                results[i] = self._lane_result(entry, out, st, ovfs[lane],
+                                               hit, dt, deadline)
             self.stats.queries += 1
             self.stats.latencies_ms.append(dt)
 
     def _lane_result(self, entry: CachedPlan, out: Relation, st: Dict,
                      ovf: Any, hit: bool, dt: float,
                      deadline: Optional[float] = None) -> ServeResult:
+        # one fetch for the flag and every counter; scalar counters
+        # become floats, per-hop vectors (the map-side cascade's
+        # hop_shuffled/hop_placed) tuples of floats
+        ovf, st = jax.device_get((ovf, st))
         overflow = bool(ovf)
-        # scalar counters become floats; per-hop vectors (the map-side
-        # cascade's hop_shuffled/hop_placed) become tuples of floats
-        measured = {k: (float(v) if jnp.ndim(v) == 0
+        measured = {k: (float(v) if np.ndim(v) == 0
                         else tuple(float(x) for x in v))
                     for k, v in st.items()}
         if overflow:

@@ -3,8 +3,11 @@
 ``overlap_chunks=C`` splits each hop's send side into C row blocks so
 block b+1's all-to-all can overlap block b's local join.  The schedule
 must change *nothing observable*: same output tuples, same overflow
-flag, bit-equal stats (the Shares/cascade accounting is per-tuple, and
-chunking moves the same tuples).  These tests pin that across every
+flag, bit-equal accounting stats (the Shares/cascade accounting is
+per-tuple, and chunking moves the same tuples).  Only the live-row
+counter differs: the chunked schedule fills a receive buffer and a join
+output per chunk, so it holds at least the staged schedule's buffer and
+live rows.  These tests pin that across every
 executor entry point on SimGrid; ``tests/_query_shard_check.py`` pins
 the same equality (plus the collective structure of the lowering) on a
 real multi-device ShardGrid.
@@ -24,7 +27,7 @@ from repro.core import (ChainCaps, ChainQuery, JoinQuery, Relation, SimGrid,
                         query_table_inputs, two_way_join)
 from repro.core.cost_model import (hop_time_overlapped, hop_time_staged,
                                    overlap_hidden_fraction)
-from repro.core.shuffle import concat_rows, split_rows
+from repro.core.shuffle import FILL_KEYS, concat_rows, split_rows
 
 CHUNK_COUNTS = (2, 3, 5)
 
@@ -49,7 +52,10 @@ def assert_overlap_invisible(fn, *, expect_overflow=False):
         assert got_ovf == base_ovf, c
         assert sorted(got_st) == sorted(base_st), c
         for k in base_st:
-            assert np.array_equal(got_st[k], base_st[k]), (c, k)
+            if k in FILL_KEYS:
+                assert got_st[k] >= base_st[k], (c, k)
+            else:
+                assert np.array_equal(got_st[k], base_st[k]), (c, k)
         # Under overflow only the flag and the accounting are
         # schedule-invariant: truncation hits *pre-filter* matches
         # (cycle-closing predicates filter after the capacity cut), so
