@@ -48,6 +48,11 @@ def partition_ranks(bucket: jnp.ndarray, valid: jnp.ndarray, n_buckets: int
     Returns (order, sorted_bucket, rank) where ``order`` stably sorts
     elements by bucket (invalid last), ``rank[i]`` is the index of
     sorted element i within its bucket.
+
+    The sorted keys come in runs, one per bucket, so a row's rank is its
+    distance from the head of its run: ``first`` is a running max of the
+    run heads' indices (one elementwise pass and one ``lax.cummax``, no
+    gather).
     """
     key = jnp.where(valid, bucket, n_buckets)  # invalid rows sort last
     # Rank packing (kernels.fused_join): buckets are already dense ranks,
@@ -59,8 +64,10 @@ def partition_ranks(bucket: jnp.ndarray, valid: jnp.ndarray, n_buckets: int
         order = jnp.argsort(key, stable=True)
     sorted_key = key[order]
     idx = jnp.arange(sorted_key.shape[0], dtype=jnp.int32)
-    # First occurrence of each bucket value in the sorted array.
-    first = jnp.searchsorted(sorted_key, sorted_key, side="left").astype(jnp.int32)
+    # First index of each row's bucket run: a run starts at row 0 or where
+    # the key differs from the row before it.
+    head = (sorted_key != jnp.roll(sorted_key, 1)) | (idx == 0)
+    first = jax.lax.cummax(jnp.where(head, idx, 0), axis=0)
     rank = idx - first
     return order, sorted_key, rank
 

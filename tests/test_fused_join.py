@@ -173,6 +173,75 @@ def test_partition_ranks_matches_argsort_plan():
         assert np.array_equal(np.asarray(rank), want_rank), (n, k)
 
 
+def searchsorted_ranks(bucket, valid, n_buckets):
+    """The oracle: ``partition_ranks`` with each row's run head found by
+    a binary search of the sorted keys against themselves."""
+    key = jnp.where(valid, bucket, n_buckets)
+    order = fj.partition_order(key, n_buckets)
+    if order is None:
+        order = jnp.argsort(key, stable=True)
+    sorted_key = key[order]
+    first = jnp.searchsorted(sorted_key, sorted_key, side="left")
+    rank = jnp.arange(sorted_key.shape[0], dtype=jnp.int32) - first.astype(
+        jnp.int32)
+    return order, sorted_key, rank
+
+
+def bucket_case(fill, n, k, rng):
+    """(bucket, valid) of ``n`` rows over ``k`` buckets: every row
+    invalid, every row in one bucket, or every bucket used (while n ≥ k)
+    with every seventh row invalid."""
+    if fill == "all_invalid":
+        return rng.integers(0, k, n), np.zeros(n, bool)
+    if fill == "one_bucket":
+        return np.full(n, rng.integers(0, k)), np.ones(n, bool)
+    return rng.permutation(np.arange(n) % k), np.arange(n) % 7 != 6
+
+
+@pytest.mark.parametrize("mode", ["plain", "jit", "vmap"])
+@pytest.mark.parametrize("fill", ["all_invalid", "one_bucket",
+                                  "every_bucket"])
+@pytest.mark.parametrize("k", [1, 4, 16, 64])
+@pytest.mark.parametrize("n", [0, 1, 2, 1000])
+def test_partition_ranks_matches_searchsorted(n, k, fill, mode):
+    rng = np.random.default_rng([n, k, len(fill)])
+    lanes = 16 if mode == "vmap" else 1
+    cases = [bucket_case(fill, n, k, rng) for _ in range(lanes)]
+    bucket = jnp.asarray(np.stack([b for b, _ in cases]), jnp.int32)
+    valid = jnp.asarray(np.stack([v for _, v in cases]))
+    if mode == "vmap":
+        got = jax.vmap(lambda b, v: partition_ranks(b, v, k))(bucket, valid)
+        want = jax.vmap(lambda b, v: searchsorted_ranks(b, v, k))(bucket,
+                                                                   valid)
+    else:
+        run = jax.jit(partition_ranks, static_argnums=2) if mode == "jit" \
+            else partition_ranks
+        got = run(bucket[0], valid[0], k)
+        want = searchsorted_ranks(bucket[0], valid[0], k)
+    for name, g, w in zip(("order", "sorted_key", "rank"), got, want):
+        assert g.dtype == w.dtype, name
+        assert np.array_equal(np.asarray(g), np.asarray(w)), name
+
+
+@pytest.mark.parametrize("cap", [25, 24])
+def test_partition_matches_searchsorted_at_capacity(cap, monkeypatch):
+    # 100 rows over 4 buckets, 25 in each: exactly at capacity (no
+    # overflow) at 25, one row over it in every bucket at 24.
+    from repro.core import local
+
+    rng = np.random.default_rng(6)
+    bucket = jnp.asarray(rng.permutation(np.arange(100) % 4), jnp.int32)
+    r = rel(rng.integers(0, 50, 100))
+    got, got_ovf = local.partition(r, bucket, 4, cap)
+    monkeypatch.setattr(local, "partition_ranks", searchsorted_ranks)
+    want, want_ovf = local.partition(r, bucket, 4, cap)
+    assert bool(got_ovf) == bool(want_ovf) == (cap < 25)
+    assert np.array_equal(np.asarray(got.valid), np.asarray(want.valid))
+    for name in want.names:
+        assert np.array_equal(np.asarray(got.cols[name]),
+                              np.asarray(want.cols[name])), name
+
+
 def test_probe_counts_interpret_matches_ref():
     rng = np.random.default_rng(4)
     sorted_keys = jnp.sort(jnp.asarray(rng.integers(0, 40, 128), jnp.int32))

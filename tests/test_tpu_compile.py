@@ -4,7 +4,8 @@ No chip is needed: the TPU compiler is installed and compiles for a
 described ``v5e:2x2`` topology.  Each test compiles one kernel over
 2^18 rows, plainly and vmapped over 16 reducers (how ``SimGrid`` runs
 it), and checks that the compiled program calls the kernel as a
-``tpu_custom_call``.  Interpret-mode tests cannot see what this sees:
+``tpu_custom_call``; one more checks that ``partition_ranks`` compiles
+to a program with no ``while``.  Interpret-mode tests cannot see what this sees:
 block shapes Mosaic refuses, 64-bit lanes the chip does not have.
 
 The topology is described inside a fixture, never at import: only one
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.local import partition_ranks
 from repro.kernels import fused_join as fj
 from repro.kernels import hash_partition as hp
 from repro.kernels import segment_sum as ss
@@ -87,6 +89,19 @@ def test_probe_counts_compiles(one_chip, reducers):
                  _shape(one_chip, jnp.int32, reducers),
                  _shape(one_chip, jnp.int32, reducers))
     _assert_kernel(c, "probe_counts")
+
+
+def test_partition_ranks_has_no_loop(one_chip):
+    """The ranks within each bucket come from a running max of run
+    heads: no ``while`` in the program.  A binary search of the sorted
+    keys lowers to a loop of gathers over every lane's whole buffer."""
+    rows = 1 << 16
+    c = _compile(lambda b, v: partition_ranks(b, v, 16), REDUCERS,
+                 jax.ShapeDtypeStruct((REDUCERS, rows), jnp.int32,
+                                      sharding=one_chip),
+                 jax.ShapeDtypeStruct((REDUCERS, rows), jnp.bool_,
+                                      sharding=one_chip))
+    assert "while(" not in c.as_text()
 
 
 def test_x64_keys_compile_or_refuse(one_chip):
