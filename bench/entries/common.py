@@ -7,7 +7,8 @@ import numpy as np
 
 
 def output_columns(query) -> tuple:
-    """(a, d, count) for the aggregated chain, (a, b, c, d) otherwise."""
+    """(*keys, count) for an aggregated query, every attribute otherwise:
+    (a, d, count) and (a, b, c, d) for the chain."""
     if query.aggregate is not None:
         return tuple(query.aggregate.keys) + (query.aggregate.out,)
     return tuple(query.attrs)

@@ -35,7 +35,8 @@ class Entry:
     def submit(self, tables, stats) -> dict:
         """One query: submit, then fetch the output to the host."""
         with jax.profiler.TraceAnnotation("bench.submit"):
-            res = self.engine.submit(self.query, [tables] * 3, stats=stats)
+            res = self.engine.submit(
+                self.query, [tables] * self.query.n_relations, stats=stats)
         out = None
         if res.ok:
             with jax.profiler.TraceAnnotation("bench.fetch"):

@@ -5,7 +5,9 @@
 Everything a cell is made of is data, found by name: the cell in
 ``BENCHMARK.json``; its configuration (``file`` of the configuration
 entry) with the graph, the reducers and the entry into the program; its
-traffic (``bench/traffic/<name>.json``); the entry
+traffic (``bench/traffic/<name>.json``) with the query, named for one of
+``JoinQuery``'s constructors and its number of relations (``chain3``,
+``cycle3``, ``star4``), each relation fed the same edge list; the entry
 (``bench/entries/<entry>.py``); and each per-layer metric's reader
 (``bench/metrics/<metric>.py``).
 
@@ -24,6 +26,7 @@ import argparse
 import importlib
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -37,6 +40,7 @@ CACHE_DIR = ROOT / ".bench_cache" / "jax"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+QUERY_NAME = re.compile(r"(chain|cycle|star)(\d+)")
 
 
 class NoChip(RuntimeError):
@@ -133,10 +137,20 @@ def memory_peak_bytes(devices) -> int | None:
 # -- one run -----------------------------------------------------------------
 
 def make_query(traffic: dict):
+    """The traffic's query: ``<kind><n>`` is ``JoinQuery.<kind>(n)``."""
     from repro.core import JoinQuery
-    if traffic["query"] != "chain3":
+    name = QUERY_NAME.fullmatch(traffic["query"])
+    if name is None:
         raise ValueError(f"unknown query {traffic['query']!r}")
-    return JoinQuery.chain(3, aggregate=traffic["aggregate"])
+    kind, n = name.groups()
+    return getattr(JoinQuery, kind)(int(n), aggregate=traffic["aggregate"])
+
+
+def structure(query):
+    """The query's shape as plain names, the form the reference takes."""
+    import reference
+    keys = query.aggregate.keys if query.aggregate is not None else ()
+    return reference.Structure(query.attrs, query.relations, tuple(keys))
 
 
 def run(cell: dict, seed: int, seconds: float, trace: bool,
@@ -159,7 +173,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
 
     # set-up: the client's data and statistics, the program, a warm query
     tables = graphs.query_tables(conf["graph"], seed, traffic["variants"])
-    stats = [query_stats_exact(query, [t] * 3) for t in tables]
+    stats = [query_stats_exact(query, [t] * query.n_relations)
+             for t in tables]
     marks.append(time.perf_counter())
     entry = (entry_cls or entry_class(conf["entry"]))(query, conf, devices)
     warm = entry.submit(tables[0], stats[0])
@@ -200,7 +215,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     del entry
 
     # the check: every answer of the window against the reference
-    want = [reference.answer(s, d, traffic["aggregate"]) for s, d in tables]
+    shape = structure(query)
+    want = [reference.answer(s, d, traffic["aggregate"], query=shape)
+            for s, d in tables]
     columns = output_columns(query)
     not_ok, mismatched, failed, gap = 0, 0, 0, 0.0
     for v, q in done:
@@ -229,8 +246,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     else:
-        paths = [reference.path_total(w, traffic["aggregate"]) for w in want]
-        values = {"join_tuples_per_s": sum(paths[v] for v, _ in done)
+        tuples = [reference.path_total(w, traffic["aggregate"]) for w in want]
+        values = {"join_tuples_per_s": sum(tuples[v] for v, _ in done)
                   / window_s, "setup_s": setup_s}
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                    for m in cell["end_to_end"]}

@@ -168,6 +168,76 @@ def test_relabelled_variants_ask_for_the_same_work(bench, reference):
     assert not np.array_equal(tables[0][0], tables[1][0])
 
 
+# -- queries by name, and the reference for each shape -------------------------
+
+@pytest.mark.parametrize("name,aggregate", list(itertools.product(
+    ["chain3", "cycle3", "star3"], [True, False])))
+def test_make_query_by_name(bench, name, aggregate):
+    from repro.core import JoinQuery
+    query = bench.make_query({"query": name, "aggregate": aggregate})
+    assert query == getattr(JoinQuery, name[:-1])(3, aggregate=aggregate)
+    shape = bench.structure(query)
+    assert shape.relations == query.relations
+    assert shape.keys == (query.aggregate.keys if aggregate else ())
+
+
+@pytest.mark.parametrize("name", ["triangle", "chain", "clique3", "cycle3x",
+                                  " chain3"])
+def test_make_query_refuses_unknown_names(bench, name):
+    with pytest.raises(ValueError, match="unknown query"):
+        bench.make_query({"query": name, "aggregate": True})
+
+
+def one_graph(spec, seed):
+    graphs = importlib.import_module("graphs")
+    return graphs.query_tables(dict(spec, structure_seed=0), seed, 1)[0]
+
+
+@pytest.mark.parametrize("scale", [5, 6])
+def test_reference_cycle_against_dense_matrix(bench, reference, scale):
+    """Closed 3-walks: diag(A^3) for each node, trace(A^3) in all."""
+    from repro.core import JoinQuery
+    src, dst = one_graph({"scale": scale, "edge_factor": 14.0, "a": 0.585},
+                         2**31 + scale)
+    adj = np.zeros((1 << scale,) * 2, np.int64)
+    adj[src, dst] = 1
+    walks = np.diag(adj @ adj @ adj)
+    shape = bench.structure(JoinQuery.cycle(3, aggregate=True))
+    agg = reference.answer(src, dst, True, query=shape)
+    assert agg.shape[1] == 2
+    assert {int(a): n for a, n in agg} == {
+        a: n for a, n in enumerate(walks.tolist()) if n}
+    rows = reference.answer(src, dst, False, query=shape)
+    assert len(rows) == walks.sum() == reference.path_total(agg, True)
+    a, b, c = rows.T
+    assert adj[a, b].all() and adj[b, c].all() and adj[c, a].all()
+    assert len(np.unique(rows, axis=0)) == len(rows)
+    bumped = agg.copy()
+    bumped[-1, -1] += 3
+    assert reference.max_count_gap(bumped, agg) == 3
+    assert reference.max_count_gap(agg[1:], agg) == agg[0, -1]
+
+
+@pytest.mark.parametrize("scale", [4, 5])
+def test_reference_star_against_brute_force(bench, reference, scale):
+    """Every triple of out-neighbours of every hub."""
+    from repro.core import JoinQuery
+    src, dst = one_graph({"scale": scale, "edge_factor": 3.0, "a": 0.5},
+                         2**31 + scale)
+    out = {}
+    for s, d in zip(src.tolist(), dst.tolist()):
+        out.setdefault(s, []).append(d)
+    want = sorted((a, *leaves) for a, ns in out.items()
+                  for leaves in itertools.product(ns, repeat=3))
+    shape = bench.structure(JoinQuery.star(3, aggregate=True))
+    assert reference.answer(src, dst, False, query=shape).tolist() == [
+        list(t) for t in want]
+    agg = reference.answer(src, dst, True, query=shape)
+    assert {int(a): n for a, n in agg} == {a: len(ns) ** 3
+                                            for a, ns in out.items()}
+    assert reference.path_total(agg, True) == len(want)
+
+
 # -- the trace reduction -------------------------------------------------------
 
 def test_reduce_on_a_synthetic_trace(tracing):
@@ -303,6 +373,34 @@ def test_one_chip_cell_rehearsal(bench, interpret_kernels, name, fault):
     if fault == "control":
         # the truncated answers came back, and the comparison caught them
         assert r["checks"]["queries_not_ok"]["value"] == 0
+
+
+@pytest.mark.parametrize("fault", [None, "altered_answer"])
+def test_cycle_cell_from_data_alone(bench, interpret_kernels, fault):
+    """A cell of per-node closed-walk counts (``cycle3``, aggregated) on a
+    livejournal-spec graph, made of nothing but a cell entry, its
+    configuration and its traffic, none of them in ``BENCHMARK.json``,
+    through the serving engine's entry."""
+    import jax
+    base = bench.load_cell("amazon_k16.agg")
+    config = dict(copy.deepcopy(base["config_data"]), name="livejournal_k16")
+    config["graph"].update(scale=5, edge_factor=14.0, a=0.585)
+    cell = {"name": "livejournal_k16.cycle3", "config": config["name"],
+            "traffic": "cycle3", "chips": 1, "config_data": config,
+            "traffic_data": dict(base["traffic_data"], query="cycle3",
+                                 aggregate=True),
+            "end_to_end": base["end_to_end"], "per_layer": []}
+    cls = bench.entry_class(config["entry"])
+    r = bench.run(cell, 2**31 + 9, 0.2, False, devices=jax.devices(),
+                  entry_cls=faulty(cls, fault))
+    assert r["attempted"] >= 1
+    assert r["correct"] is (fault is None)
+    assert r["metrics"]["join_tuples_per_s"]["value"] > 0
+    if fault is None:
+        assert r["failed"] == 0 and r["compiles"]["window"] == 0
+    else:
+        assert r["checks"]["max_count_gap"]["value"] >= 1
+        assert r["checks"]["mismatched_rows"]["value"] > 0
 
 
 def test_bf16_control_rounds_counts_past_256(bench):
